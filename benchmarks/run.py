@@ -49,6 +49,7 @@ def main() -> None:
     args = ap.parse_args()
     todo = args.only or BENCHES
     print("name,us_per_call,derived")
+    failed = []
     for name in todo:
         t0 = time.time()
         try:
@@ -72,7 +73,10 @@ def main() -> None:
                 __import__(f"benchmarks.{mod}", fromlist=["run"]).run()
         except Exception:
             print(f"{name}/ERROR,0.0,{traceback.format_exc(limit=1)!r}")
+            failed.append(name)
         print(f"# {name} done in {time.time() - t0:.1f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

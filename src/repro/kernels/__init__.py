@@ -1,10 +1,12 @@
 """Pallas TPU kernels for the GNN hot spots (+ jnp oracles).
 
-Each kernel package has kernel.py (pl.pallas_call + BlockSpec VMEM tiling,
-validated under interpret=True on CPU), ops.py (dispatching wrapper) and
-ref.py (pure-jnp oracle).  The ``fused_*`` packages fuse whole layer
-tails (gather -> aggregate, softmax -> weighted gather -> aggregate) so
-the (E, F) message array never touches HBM; ``sparse_adam`` fuses the
+Each kernel package has kernel.py (pl.pallas_call, compiled for the TPU
+and validated under interpret=True on CPU), ops.py (the wrapper that
+dispatches on ``impl`` through :mod:`.platform` and gives the Pallas path
+its ``custom_vjp``) and ref.py (pure-jnp oracle).  The ``fused_*``
+packages fuse whole layer tails (gather -> aggregate, softmax ->
+weighted gather -> aggregate) so the (E, F) message array never touches
+HBM; ``sparse_adam`` fuses the
 DistEmbedding optimizer's gather -> update -> scatter; ``pack`` is the
 packed one-shot device staging used by every device-prefetch stage
 (DESIGN.md §9).

@@ -1,125 +1,33 @@
-"""Pallas TPU kernel: fused attention tail — edge softmax + weighted
-gather + segment-sum in two pallas_calls, never materializing the
-(E, H*Dh) message array.
+"""Pallas TPU path of the fused attention tail: edge softmax -> weighted
+gather -> segment-sum, never materializing the (E, H*Dh) message array.
 
-Phase 1 reuses the edge-softmax stats kernel verbatim (online-rescaled
-per-destination max ``m`` and denominator ``d``, flash-attention style).
-
-Phase 2 fuses what used to be three HBM-bound steps (normalize ->
-gather+weight -> segment-sum) into one edge sweep: for each edge block it
-recomputes the normalized attention weight from the resident stats
-(``exp(score - m[dst]) / d[dst]``), gathers the projected source rows from
-the feature-block-resident table, applies the per-head weight (repeated
-over the head width), and folds the tile into the per-destination
-accumulator with the one-hot matmul.  The (EB, F) weighted message tile
-only ever lives in VMEM.
-
-Grid (dst_blocks, edge_blocks) with the flattened feature axis F = H*Dh
-fully resident: F is a hidden dimension (hundreds), not a graph axis, and
-the stats gather needs whole (N, H) stats blocks anyway.
+The softmax weights are (E, H) — H is the head count, a handful — so they
+are computed in XLA by the reference edge softmax (segment max and sum
+over the destinations).  The expensive part, the weighted gather of the
+projected source rows and their per-destination sum, is the fused
+gather kernel with per-head edge weights
+(:mod:`..fused_gather_aggregate.kernel`): the source table stays in HBM
+and the (EB, H*Dh) message tile only ever lives in VMEM.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from ..edge_softmax.kernel import _stats_kernel
-
-DEFAULT_EB = 512
-DEFAULT_NB = 128
+from ..edge_softmax.ref import edge_softmax_ref
+from ..fused_gather_aggregate.kernel import fused_gather_aggregate_pallas
 
 
-def _agg_kernel(src_ref, dst_ref, mask_ref, s_ref, h_ref, m_ref, d_ref,
-                out_ref, *, nb: int, dh: int, fp: int):
-    i = pl.program_id(0)          # dst block
-    k = pl.program_id(1)          # edge block (innermost: accumulation)
-
-    @pl.when(k == 0)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    src = src_ref[...]            # (EB,)
-    dst = dst_ref[...]            # (EB,) clipped global dst ids
-    mask = mask_ref[...]          # (EB,)
-    sc = s_ref[...]               # (EB, H)
-    m = m_ref[dst]                # (EB, H) gather from full stats block
-    d = d_ref[dst]
-    w = jnp.exp(sc - m) / jnp.maximum(d, 1e-30)       # (EB, H)
-    w = jnp.where(mask[:, None], w, 0.0)
-    wf = jnp.repeat(w, dh, axis=1)                    # (EB, H*Dh)
-    wf = jnp.pad(wf, ((0, 0), (0, fp - wf.shape[1])))
-    msg = h_ref[src] * wf                             # (EB, Fp) in VMEM only
-    rows = i * nb + jax.lax.broadcasted_iota(jnp.int32, (dst.shape[0], nb), 1)
-    onehot = ((dst[:, None] == rows) & mask[:, None]).astype(msg.dtype)
-    out_ref[...] += jnp.dot(onehot.T, msg,
-                            preferred_element_type=out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("num_dst", "eb", "nb",
-                                             "interpret"))
 def fused_edge_softmax_aggregate_pallas(h_proj: jnp.ndarray,
                                         scores: jnp.ndarray,
                                         edge_src: jnp.ndarray,
                                         edge_dst: jnp.ndarray,
                                         edge_mask: jnp.ndarray,
                                         num_dst: int, *,
-                                        eb: int = DEFAULT_EB,
-                                        nb: int = DEFAULT_NB,
                                         interpret: bool = True
                                         ) -> jnp.ndarray:
+    """h_proj: (V, H, Dh); scores: (E, H) -> (num_dst, H*Dh)."""
     v, h, dh = h_proj.shape
-    f = h * dh
-    e = scores.shape[0]
-    eb = min(eb, e)
-    nb = min(nb, num_dst)
-    ep = -(-e // eb) * eb
-    np_ = -(-num_dst // nb) * nb
-    fp = -(-f // 128) * 128 if f > 128 else f
-    vp = -(-v // 8) * 8
-    sc = jnp.pad(scores, ((0, ep - e), (0, 0)))
-    src_p = jnp.pad(edge_src.astype(jnp.int32), (0, ep - e))
-    dst = jnp.pad(edge_dst.astype(jnp.int32), (0, ep - e),
-                  constant_values=-1)
-    mask = jnp.pad(edge_mask.astype(jnp.bool_), (0, ep - e))
-    h2 = jnp.pad(h_proj.reshape(v, f), ((0, vp - v), (0, fp - f)))
-
-    # phase 1: per-destination (max, denominator) — the edge-softmax stats
-    m, d = pl.pallas_call(
-        functools.partial(_stats_kernel, nb=nb),
-        grid=(np_ // nb, ep // eb),
-        in_specs=[
-            pl.BlockSpec((eb,), lambda i, k: (k,)),
-            pl.BlockSpec((eb,), lambda i, k: (k,)),
-            pl.BlockSpec((eb, h), lambda i, k: (k, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((nb, h), lambda i, k: (i, 0)),
-            pl.BlockSpec((nb, h), lambda i, k: (i, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((np_, h), scores.dtype),
-                   jax.ShapeDtypeStruct((np_, h), scores.dtype)],
-        interpret=interpret,
-    )(dst, mask, sc)
-
-    # phase 2: fused normalize + weighted gather + aggregate
-    dst_c = jnp.clip(dst, 0, np_ - 1)
-    out = pl.pallas_call(
-        functools.partial(_agg_kernel, nb=nb, dh=dh, fp=fp),
-        grid=(np_ // nb, ep // eb),
-        in_specs=[
-            pl.BlockSpec((eb,), lambda i, k: (k,)),
-            pl.BlockSpec((eb,), lambda i, k: (k,)),
-            pl.BlockSpec((eb,), lambda i, k: (k,)),
-            pl.BlockSpec((eb, h), lambda i, k: (k, 0)),
-            pl.BlockSpec((vp, fp), lambda i, k: (0, 0)),
-            pl.BlockSpec((np_, h), lambda i, k: (0, 0)),
-            pl.BlockSpec((np_, h), lambda i, k: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((nb, fp), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((np_, fp), h_proj.dtype),
-        interpret=interpret,
-    )(src_p, dst_c, mask, sc, h2, m, d)
-    return out[:num_dst, :f]
+    alpha = edge_softmax_ref(scores, edge_dst, edge_mask, num_dst)
+    return fused_gather_aggregate_pallas(
+        h_proj.reshape(v, h * dh), edge_src, edge_dst, edge_mask, num_dst,
+        weights=alpha, interpret=interpret)

@@ -3,9 +3,10 @@
 The device-prefetch stage used to ship every mini-batch as ~10 independent
 ``jax.device_put`` calls of small arrays — one per field, four more per MFG
 block — so the stage was dominated by per-transfer overhead, not bandwidth.
-This module packs the whole batch into **one contiguous host arena** (one
-contiguous segment per dtype — at most four: f32 / i64 / i32 / bool) and
-issues a **single one-buffer** ``jax.device_put``; the per-field views are
+This module packs the whole batch into **one contiguous host arena** of
+32-bit words (one contiguous segment per dtype — at most four: f32 / i64 /
+i32 / bool — each starting on a word) and issues a **single one-buffer**
+``jax.device_put``; the per-field views are
 recovered *on device* by a jitted unpack whose byte offsets are
 compile-time constants (the padded-MFG capacity contract of DESIGN.md §2
 makes every shape static, so the same :class:`PackSpec` — and the same
@@ -16,18 +17,25 @@ Value contract: staging through ``pack -> device_put -> unpack`` is
 apply exactly jax's canonicalization casts (with x64 disabled an int64
 array lands as int32 either way, applied here on the host while filling
 the packed arena), and unpacking is pure static slicing + reshape +
-bitcast — ``lax.bitcast_convert_type`` from the arena's uint8 bytes back
-to each dtype is bit-exact by definition, and the bool segment is
-recovered with ``!= 0`` (exact: NumPy bool storage is 0/1 bytes).  No
-arithmetic touches the payload.
+bitcast — ``lax.bitcast_convert_type`` from the arena's words back to
+each dtype is bit-exact by definition, and the bool segment is recovered
+with ``!= 0`` (exact: NumPy bool storage is 0/1 bytes).  No arithmetic
+touches the payload.
+
+Why words and not bytes: a 32-bit segment is then an elementwise bitcast
+of a slice of the arena.  Unpacking it from bytes needs a ``(n, 4)``
+uint8 intermediate, which the TPU lays out with its minor dimension
+padded to 128 lanes — 32x the segment (a 1.7 GB feature block asked for
+56 GB).  Only sub-word segments (bool masks) still take that path, at
+their own, small, size.
 
 Layout: leaves are keyed by their "/"-joined tree path (lists by index,
 e.g. ``blocks/0/edge_src``), sorted by key within each dtype segment so
 the offset table is a pure function of the spec; dtype segments are laid
-out in descending-itemsize order, so every segment's byte offset is a
-multiple of its itemsize (alignment for free).  ``None`` leaves are
-recorded in the spec and resurface as ``None`` on unpack (a label-less
-epoch keeps its ``labels=None`` slot).
+out in descending-itemsize order, each padded to whole words, so every
+segment's byte offset is a multiple of both 4 and its itemsize.  ``None``
+leaves are recorded in the spec and resurface as ``None`` on unpack (a
+label-less epoch keeps its ``labels=None`` slot).
 """
 from __future__ import annotations
 
@@ -40,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 SEP = "/"
+WORD = 4          # the arena is uint32 words
 
 # dtypes jax silently canonicalizes when x64 is disabled; applied on the
 # host while filling the buffer so packed == per-array staging bit-for-bit
@@ -90,19 +99,26 @@ class PackSpec:
     @functools.cached_property
     def arena_layout(self) -> Tuple[Tuple[str, int, int], ...]:
         """(dtype, byte_offset, num_elements) per dtype segment of the
-        arena, in descending-itemsize order — each segment's offset is a
-        multiple of its itemsize (itemsizes are powers of two)."""
+        arena, in descending-itemsize order — each segment starts on a
+        32-bit word, so its offset is a multiple of 4 and of its itemsize
+        (itemsizes are powers of two)."""
         segs = sorted(self.buffer_sizes.items(),
                       key=lambda kv: (-np.dtype(kv[0]).itemsize, kv[0]))
         out, off = [], 0
         for dt, n in segs:
             out.append((dt, off, n))
-            off += n * np.dtype(dt).itemsize
+            off += -(-n * np.dtype(dt).itemsize // WORD) * WORD
         return tuple(out)
 
     def total_bytes(self) -> int:
+        """Payload bytes (the arena adds < 4 bytes of padding per dtype)."""
         return sum(n * np.dtype(dt).itemsize
                    for dt, n in self.buffer_sizes.items())
+
+    @property
+    def arena_words(self) -> int:
+        dt, off, n = self.arena_layout[-1]
+        return -(-(off + n * np.dtype(dt).itemsize) // WORD)
 
 
 def flatten_tree(tree: Any) -> Tuple[Dict[str, np.ndarray], Tuple[str, ...]]:
@@ -156,7 +172,7 @@ def _spec_cache(fields, none_paths) -> PackSpec:
 
 
 def pack(tree: Any) -> Tuple[PackSpec, np.ndarray]:
-    """Flatten a host batch into ONE contiguous uint8 arena (one segment
+    """Flatten a host batch into ONE contiguous uint32 arena (one segment
     per dtype, fields at static offsets within their segment)."""
     flat, none_paths = flatten_tree(tree)
     fields = []
@@ -164,8 +180,9 @@ def pack(tree: Any) -> Tuple[PackSpec, np.ndarray]:
         dt = _canon_dtype(arr.dtype)
         fields.append((path, tuple(arr.shape), dt.str))
     spec = _spec_cache(tuple(sorted(fields)), none_paths)
-    arena = np.empty(spec.total_bytes(), dtype=np.uint8)
-    views = {dt: arena[boff:boff + n * np.dtype(dt).itemsize].view(dt)
+    arena = np.zeros(spec.arena_words, dtype=np.uint32)
+    raw = arena.view(np.uint8)
+    views = {dt: raw[boff:boff + n * np.dtype(dt).itemsize].view(dt)
              for dt, boff, n in spec.arena_layout}
     for path, shape, dt, off, size in spec.layout:
         # ravel + canonicalization cast in one copy into the arena
@@ -186,12 +203,18 @@ def _unpack_fn(spec: PackSpec):
     def unpack_flat(arena: jnp.ndarray) -> Dict[str, jnp.ndarray]:
         bufs = {}
         for dt, (boff, n, nd) in segs.items():
-            raw = arena[boff:boff + n * nd.itemsize]
+            w0 = boff // WORD
+            w1 = w0 + -(-n * nd.itemsize // WORD)
+            words = arena[w0:w1]
+            if nd.itemsize > WORD:           # (n, 2) words per element
+                words = words.reshape(n, nd.itemsize // WORD)
+            elif nd.itemsize < WORD:         # WORD // itemsize per word
+                words = jax.lax.bitcast_convert_type(
+                    words, np.dtype(f"u{nd.itemsize}")).reshape(-1)[:n]
             if nd == np.dtype(bool):
-                bufs[dt] = raw != 0          # exact: bool bytes are 0/1
+                bufs[dt] = words != 0        # exact: bool bytes are 0/1
             else:
-                bufs[dt] = jax.lax.bitcast_convert_type(
-                    raw.reshape(n, nd.itemsize), nd)
+                bufs[dt] = jax.lax.bitcast_convert_type(words, nd)
         out = {}
         for path, shape, dt, off, size in spec.layout:
             out[path] = bufs[dt][off:off + size].reshape(shape)
@@ -214,7 +237,7 @@ def unpack(spec: PackSpec, arena: jnp.ndarray) -> Any:
 
 
 class PackedBatch:
-    """One staged mini-batch: the spec + its device-resident uint8 arena.
+    """One staged mini-batch: the spec + its device-resident uint32 arena.
 
     ``unpack()`` recovers the nested device tree (cached — slicing a
     resident buffer is cheap but not free); ``buffers`` is the single
@@ -253,10 +276,7 @@ def _stage_arena(arena: np.ndarray) -> jnp.ndarray:
     # accelerator it would land the buffer on the HOST device, so there
     # we keep device_put (one H2D transfer of the whole arena).
     if _cpu_backend():
-        try:
-            return jnp.from_dlpack(arena)
-        except Exception:  # pragma: no cover - old jax without dlpack
-            pass
+        return jnp.from_dlpack(arena)
     return jax.device_put(arena)
 
 
@@ -264,7 +284,7 @@ def device_stage(tree: Any, packed: bool = True):
     """The shared device-prefetch helper (both mini-batch pipelines and
     the LM token stream stage through here).
 
-    ``packed=True``: pack -> ONE single-buffer transfer of the uint8
+    ``packed=True``: pack -> ONE single-buffer transfer of the uint32
     arena -> :class:`PackedBatch`.  ``packed=False``: the legacy
     per-array path — one ``device_put`` per leaf, ``None`` leaves passed
     through — kept as the ablation baseline the benchmarks compare

@@ -3,11 +3,14 @@
 The KVStore servers apply Adam to exactly the rows a mini-batch touched.
 Expressed naively on an accelerator that is gather / three elementwise
 updates / scatter — five HBM round trips over the full tables.  Here the
-whole update runs as scalar-prefetch-driven Pallas programs: the row ids
-are prefetched to SMEM, each grid step DMAs one (1, D) row of w/m/v in,
-updates it, and writes it back through ``input_output_aliases`` — rows
-never touched keep their exact bytes because the output IS the input
-buffer.
+whole update runs as two Pallas programs over tiles of RB touched rows:
+the tables stay in HBM (``memory_space=pl.ANY``), the tile's row ids ride
+in SMEM, and each grid step DMAs its RB rows of the tables into VMEM one
+row copy at a time, updates them, and DMAs them back into the outputs,
+which alias the inputs through ``input_output_aliases`` — rows never
+touched keep their exact bytes because the output IS the input buffer.
+(One-row BlockSpecs would be simpler, but the TPU compiler refuses a
+block whose second-to-last dimension is not a multiple of 8.)
 
 Why TWO pallas_calls (products, then update+scatter) and not one: the
 bitwise contract.  The server-side NumPy update is the repo's oracle, and
@@ -37,36 +40,74 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _products_kernel(rows_ref, m_ref, v_ref, pm_ref, pv_ref, *,
-                     beta1: float, beta2: float):
-    del rows_ref                    # consumed by the index_maps
-    pm_ref[...] = beta1 * m_ref[...]
-    pv_ref[...] = beta2 * v_ref[...]
+# row ids ride in SMEM blocks of 1024 (XLA tiles 1-D int32 arrays by
+# 1024); each grid step (i, j) handles RB of block i's rows
+ID_BLOCK = 1024
+RB = 256
+LANES = 128
 
 
-def _update_kernel(rows_ref, w_ref, m_tab_ref, v_tab_ref, pm_ref, pv_ref,
-                   cm_ref, cv_ref, bc1_ref, bc2_ref,
-                   w_out, m_out, v_out, *, lr: float, eps: float):
-    del rows_ref, m_tab_ref, v_tab_ref    # aliased outputs / index_maps
+def _copy_rows(n, rows_ref, base, src, dst, sem, *, gather: bool):
+    """Start then wait n row DMAs between table ``src``/``dst`` rows
+    ``rows_ref[base + r]`` and tile row r (gather: table -> tile)."""
+    def copy(r, row):
+        if gather:
+            return pltpu.make_async_copy(src.at[pl.ds(row, 1)],
+                                         dst.at[pl.ds(r, 1)], sem)
+        return pltpu.make_async_copy(src.at[pl.ds(r, 1)],
+                                     dst.at[pl.ds(row, 1)], sem)
+
+    def start(r, carry):
+        copy(r, rows_ref[base + r]).start()
+        return carry
+
+    def wait(r, carry):
+        copy(0, 0).wait()
+        return carry
+
+    lax.fori_loop(0, n, start, 0)
+    lax.fori_loop(0, n, wait, 0)
+
+
+def _tile_rows(num_rows: int):
+    """(offset of this tile in its SMEM id block, live rows in the tile)."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    base = j * RB
+    n = jnp.clip(num_rows - i * ID_BLOCK - base, 0, RB)
+    return base, n
+
+
+def _products_kernel(rows_ref, m_hbm, v_hbm, pm_ref, pv_ref, m_buf, v_buf,
+                     sem, *, beta1: float, beta2: float, num_rows: int):
+    base, n = _tile_rows(num_rows)
+    _copy_rows(n, rows_ref, base, m_hbm, m_buf, sem.at[0], gather=True)
+    _copy_rows(n, rows_ref, base, v_hbm, v_buf, sem.at[1], gather=True)
+    pm_ref[...] = beta1 * m_buf[...]
+    pv_ref[...] = beta2 * v_buf[...]
+
+
+def _update_kernel(rows_ref, w_hbm, m_hbm, v_hbm, pm_ref, pv_ref, cm_ref,
+                   cv_ref, bc1_ref, bc2_ref, w_out, m_out, v_out,
+                   w_buf, m_buf, v_buf, sem, *, lr: float, eps: float,
+                   num_rows: int):
+    del m_hbm, v_hbm                      # aliased by m_out / v_out
+    base, n = _tile_rows(num_rows)
+    _copy_rows(n, rows_ref, base, w_hbm, w_buf, sem.at[0], gather=True)
     mm = pm_ref[...] + cm_ref[...]
     vv = pv_ref[...] + cv_ref[...]
     mhat = mm / bc1_ref[...]
     vhat = vv / bc2_ref[...]
-    w_out[...] = w_ref[...] - lr * mhat / (jnp.sqrt(vhat) + eps)
-    m_out[...] = mm
-    v_out[...] = vv
-
-
-def _row_spec(d):
-    return pl.BlockSpec((1, d), lambda i, rows: (rows[i], 0))
-
-
-def _seq_spec(d):
-    return pl.BlockSpec((1, d), lambda i, rows: (i, 0))
+    w_buf[...] = w_buf[...] - lr * mhat / (jnp.sqrt(vhat) + eps)
+    m_buf[...] = mm
+    v_buf[...] = vv
+    for k, (buf, out) in enumerate(((w_buf, w_out), (m_buf, m_out),
+                                    (v_buf, v_out))):
+        _copy_rows(n, rows_ref, base, buf, out, sem.at[k], gather=False)
 
 
 def sparse_adam_pallas(w: jnp.ndarray, m: jnp.ndarray, v: jnp.ndarray,
@@ -82,30 +123,45 @@ def sparse_adam_pallas(w: jnp.ndarray, m: jnp.ndarray, v: jnp.ndarray,
     """
     n, d = w.shape
     r = rows.shape[0]
-    rows = rows.astype(jnp.int32)
-
-    grid_spec = lambda n_in: pltpu.PrefetchScalarGridSpec(   # noqa: E731
-        num_scalar_prefetch=1, grid=(r,), in_specs=n_in[0],
-        out_specs=n_in[1])
+    rp = -(-r // ID_BLOCK) * ID_BLOCK
+    dp = -(-d // LANES) * LANES
+    rows = jnp.pad(jnp.asarray(rows, jnp.int32), (0, rp - r))
+    w, m, v = (jnp.pad(x, ((0, 0), (0, dp - d))) for x in (w, m, v))
+    cm, cv, bc1, bc2 = (jnp.pad(x, ((0, rp - r), (0, dp - d)),
+                                constant_values=1)
+                        for x in (cm, cv, bc1, bc2))
+    grid = (rp // ID_BLOCK, ID_BLOCK // RB)
+    ids = pl.BlockSpec((ID_BLOCK,), lambda i, j: (i,),
+                       memory_space=pltpu.SMEM)
+    table = pl.BlockSpec(memory_space=pl.ANY)
+    tile = pl.BlockSpec((RB, dp),
+                        lambda i, j: (i * (ID_BLOCK // RB) + j, 0))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"))
 
     pm, pv = pl.pallas_call(
-        functools.partial(_products_kernel, beta1=beta1, beta2=beta2),
-        grid_spec=grid_spec(([_row_spec(d), _row_spec(d)],
-                             [_seq_spec(d), _seq_spec(d)])),
-        out_shape=[jax.ShapeDtypeStruct((r, d), w.dtype)] * 2,
-        interpret=interpret,
+        functools.partial(_products_kernel, beta1=beta1, beta2=beta2,
+                          num_rows=r),
+        grid=grid, in_specs=[ids, table, table], out_specs=[tile, tile],
+        out_shape=[jax.ShapeDtypeStruct((rp, dp), w.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((RB, dp), w.dtype)] * 2
+        + [pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=params, interpret=interpret,
+        name="sparse_adam_products",
     )(rows, m, v)
 
-    # aliased scatter: inputs w/m/v (operand indices 1..3 — the scalar-
-    # prefetch rows are operand 0) become the outputs, so untouched rows
-    # pass through bit-exactly without ever being read
+    # aliased scatter: inputs w/m/v (operand indices 1..3 — the row ids
+    # are operand 0) become the outputs, so untouched rows pass through
+    # bit-exactly without ever being read
     w2, m2, v2 = pl.pallas_call(
-        functools.partial(_update_kernel, lr=lr, eps=eps),
-        grid_spec=grid_spec((
-            [_row_spec(d)] * 3 + [_seq_spec(d)] * 6,
-            [_row_spec(d)] * 3)),
-        out_shape=[jax.ShapeDtypeStruct((n, d), w.dtype)] * 3,
+        functools.partial(_update_kernel, lr=lr, eps=eps, num_rows=r),
+        grid=grid, in_specs=[ids] + [table] * 3 + [tile] * 6,
+        out_specs=[table] * 3,
+        out_shape=[jax.ShapeDtypeStruct((n, dp), w.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((RB, dp), w.dtype)] * 3
+        + [pltpu.SemaphoreType.DMA((3,))],
         input_output_aliases={1: 0, 2: 1, 3: 2},
-        interpret=interpret,
+        compiler_params=params, interpret=interpret,
+        name="sparse_adam_update",
     )(rows, w, m, v, pm, pv, cm, cv, bc1, bc2)
-    return w2, m2, v2
+    return w2[:, :d], m2[:, :d], v2[:, :d]

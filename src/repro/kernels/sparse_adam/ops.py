@@ -15,15 +15,11 @@ must not be recomputed on device; see kernel.py).
 """
 from __future__ import annotations
 
-import jax
 import numpy as np
 
+from ..platform import on_tpu, resolve_impl
 from .kernel import sparse_adam_pallas
 from .ref import sparse_adam_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def sparse_adam_apply(w: np.ndarray, m: np.ndarray, v: np.ndarray,
@@ -37,8 +33,7 @@ def sparse_adam_apply(w: np.ndarray, m: np.ndarray, v: np.ndarray,
     like the oracle); rows: (R,) unique local row ids; grad: (R, D) f32
     coalesced gradients.
     """
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
+    impl = resolve_impl(impl)
     t[rows] += 1
     tr = t[rows].astype(np.float32)[:, None]
     bc1 = 1 - beta1 ** tr
@@ -47,8 +42,6 @@ def sparse_adam_apply(w: np.ndarray, m: np.ndarray, v: np.ndarray,
         sparse_adam_ref(w, m, v, rows, grad, bc1, bc2, beta1=beta1,
                         beta2=beta2, lr=lr, eps=eps)
         return
-    if impl != "pallas":
-        raise ValueError(f"unknown impl {impl!r}")
     if w.dtype != np.float32:
         # non-f32 tables keep the NumPy path: the bitwise contract is
         # only defined for f32 (and the kernel assumes one dtype)
@@ -64,7 +57,7 @@ def sparse_adam_apply(w: np.ndarray, m: np.ndarray, v: np.ndarray,
         np.broadcast_to(bc1, (len(rows), d)).astype(np.float32),
         np.broadcast_to(bc2, (len(rows), d)).astype(np.float32),
         beta1=beta1, beta2=beta2, lr=lr, eps=eps,
-        interpret=not _on_tpu())
+        interpret=not on_tpu())
     # scatter back into the server's storage (the kernel already scattered
     # device-side via aliasing; these copies land the bytes in host numpy)
     np.copyto(w, np.asarray(w2))

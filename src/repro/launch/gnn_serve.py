@@ -19,6 +19,8 @@ import argparse
 import json
 import time
 
+from .compile_cache import use_compile_cache
+
 
 def build_parser() -> argparse.ArgumentParser:
     """The serving CLI. Every flag here must be documented in the
@@ -196,6 +198,7 @@ def run_serving(args) -> dict:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     if args.offline:
         return run_offline(args)
     return run_serving(args)

@@ -20,6 +20,8 @@ import json
 import os
 import time
 
+from .compile_cache import use_compile_cache
+
 
 def run_gnn(args):
     import jax
@@ -309,6 +311,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
+    use_compile_cache()
     from ..configs import GNN_ARCHS
     if args.arch in GNN_ARCHS:
         run_gnn(args)

@@ -16,9 +16,9 @@ from ...kernels import (fused_edge_softmax_aggregate, fused_gather_aggregate,
                         segment_sum)
 
 
-def _degrees(edge_dst, edge_mask, num_dst):
+def _degrees(edge_dst, edge_mask, num_dst, impl):
     ones = edge_mask.astype(jnp.float32)[:, None]
-    deg = segment_sum(ones, edge_dst, edge_mask, num_dst)[:, 0]
+    deg = segment_sum(ones, edge_dst, edge_mask, num_dst, impl=impl)[:, 0]
     return jnp.maximum(deg, 1.0)
 
 
@@ -31,7 +31,7 @@ def sage_layer(params, h_src: jnp.ndarray, block: dict, num_dst: int,
     # materializes on the pallas path (ref path = the old two-step jaxpr)
     agg = fused_gather_aggregate(h_src, edge_src, edge_dst, edge_mask,
                                  num_dst, impl=impl)
-    agg = agg / _degrees(edge_dst, edge_mask, num_dst)[:, None]
+    agg = agg / _degrees(edge_dst, edge_mask, num_dst, impl)[:, None]
     h_self = h_src[:num_dst]
     out = h_self @ params["w_self"] + agg @ params["w_neigh"] + params["b"]
     return activation(out) if activation is not None else out
@@ -86,6 +86,6 @@ def rgcn_layer(params, h_src: jnp.ndarray, block: dict, num_dst: int,
             em = edge_mask & (edge_types == r)
         proj = h_src @ params["w_rel"][r]                   # (cap_src, d_out)
         agg = fused_gather_aggregate(proj, es, ed, em, num_dst, impl=impl)
-        agg = agg / _degrees(ed, em, num_dst)[:, None]
+        agg = agg / _degrees(ed, em, num_dst, impl)[:, None]
         out = out + agg
     return activation(out) if activation is not None else out

@@ -20,6 +20,7 @@ def _run(body: str):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import AxisType
     """) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", code],
                        env={**os.environ, "PYTHONPATH": SRC},
@@ -37,7 +38,8 @@ def test_moe_sharded_matches_local_oracle():
         from repro.models.lm.model import _moe_params
         R.AXIS_SIZES.update({"data": 2, "model": 4})
         set_rules(AxisRules(batch_axes=("data",), model_axis_size=4))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         for ne in (8, 6):   # divisible and padded expert counts
             cfg = LMConfig(name="t", arch_type="moe", num_layers=1,
                            d_model=32, num_heads=4, num_kv_heads=2, d_ff=0,
@@ -72,7 +74,8 @@ def test_mini_dryrun_lowers_on_mesh():
         from repro.optim.optimizers import AdamWState
         R.AXIS_SIZES.update({"data": 2, "model": 4})
         set_rules(AxisRules(batch_axes=("data",), model_axis_size=4))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = dataclasses.replace(smoke_variant(get_config("llama3-8b")),
                                   num_layers=2, remat=True)
         params_abs = abstract_params(cfg)
@@ -120,7 +123,8 @@ def test_sharded_train_step_matches_single_device():
         batch = {"tokens": jnp.asarray(
             rng.integers(0, cfg.vocab_size, (4, 64)))}
         p1, _, m1 = jax.jit(step)(params, opt, batch)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ps = param_pspecs(params, fsdp=False)
         sh = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                     is_leaf=lambda x: isinstance(x, P))
@@ -147,7 +151,8 @@ def test_moe_a2a_dispatch_matches_local_oracle():
         from repro.models.lm.model import _moe_params
         R.AXIS_SIZES.update({"data": 2, "model": 4})
         set_rules(AxisRules(batch_axes=("data",), model_axis_size=4))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         for ne in (8, 6):
             cfg = LMConfig(name="t", arch_type="moe", num_layers=1,
                            d_model=32, num_heads=4, num_kv_heads=2, d_ff=0,
@@ -178,7 +183,8 @@ def test_pure_fsdp_mode_lowers():
         set_rules(AxisRules(batch_axes=("data", "model"), fsdp_axis=None,
                             seq_shard_activations=False, pure_fsdp=True,
                             model_axis_size=4))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = dataclasses.replace(smoke_variant(get_config("llama3-8b")),
                                   num_layers=2, d_model=256)
         params_abs = abstract_params(cfg)

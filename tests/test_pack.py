@@ -3,9 +3,9 @@
 The contract under test: ``pack -> single device transfer -> unpack`` is
 *byte-identical* to per-array ``jax.device_put`` of the same tree — same
 dtypes (jax's x64 canonicalization applied host-side), same shapes, same
-bytes — with ``None`` leaves restored, the arena laid out so every dtype
-segment is itemsize-aligned, and the spec/offset table a pure function of
-the batch's (path, shape, dtype) set.
+bytes — with ``None`` leaves restored, the arena laid out in 32-bit words
+so every dtype segment is word- and itemsize-aligned, and the spec/offset
+table a pure function of the batch's (path, shape, dtype) set.
 """
 import importlib
 
@@ -58,9 +58,11 @@ def test_roundtrip_byte_identical_to_per_array():
     out = staged.unpack()
     assert out["labels"] is None
     assert out["blocks"][0]["edge_types"] is None
-    # the staged form is ONE device buffer: the uint8 arena
-    assert staged.buffers.dtype == jnp.uint8
-    assert staged.buffers.shape == (staged.total_bytes(),)
+    # the staged form is ONE device buffer: the uint32 arena
+    assert staged.buffers.dtype == jnp.uint32
+    assert staged.buffers.shape == (staged.spec.arena_words,)
+    assert 0 <= 4 * staged.spec.arena_words - staged.total_bytes() \
+        < 4 * staged.spec.num_buffers
 
 
 def test_unpack_cached_and_getitem():
@@ -72,18 +74,22 @@ def test_unpack_cached_and_getitem():
 
 def test_arena_segments_itemsize_aligned_and_disjoint():
     spec, arena = pack(_batch_tree(2))
-    assert arena.dtype == np.uint8 and arena.nbytes == spec.total_bytes()
+    assert arena.dtype == np.uint32 and arena.size == spec.arena_words
     end = 0
     seen_itemsize = None
+    payload = 0
     for dt, boff, n in spec.arena_layout:
         item = np.dtype(dt).itemsize
         assert boff % item == 0, f"segment {dt} misaligned at byte {boff}"
-        assert boff == end, "segments must tile the arena with no gaps"
+        # each segment starts on the first word after the previous one
+        assert boff == -(-end // 4) * 4, "segments must tile the words"
         end = boff + n * item
+        payload += n * item
         # descending-itemsize order is what makes alignment automatic
         assert seen_itemsize is None or item <= seen_itemsize
         seen_itemsize = item
-    assert end == spec.total_bytes()
+    assert -(-end // 4) == spec.arena_words
+    assert payload == spec.total_bytes()
 
 
 def test_spec_is_pure_function_of_fields_and_cached():
