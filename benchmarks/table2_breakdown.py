@@ -67,6 +67,10 @@ def _breakdown(tag: str, ds, cfg, t_load: float, epochs: int,
              float(sampling["transport"]["remote_requests"]),
              f"coalescing_factor={req['coalescing_factor']:.1f};"
              f"owner_requests={req['owner_requests']}")
+    consumer = stage_stats.pop("consumer")
+    csv_line(f"{tag}/consumer_wait",
+             consumer["wait_in_s"] * 1e6 / max(consumer["items"], 1),
+             f"items={consumer['items']}")
     for name, st in stage_stats.items():
         csv_line(f"{tag}/stage/{name}",
                  st["busy_s"] * 1e6 / max(st["items"], 1),

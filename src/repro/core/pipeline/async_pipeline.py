@@ -26,7 +26,9 @@ no-pipelining baseline used for the Fig. 14 ablation.
 
 Per-stage wall-time and occupancy counters feed the Table-2-style breakdown
 benchmark; under pools the counters aggregate over all of a stage's
-workers (guarded by a per-stage lock).
+workers (guarded by a per-stage lock).  The consumer's own counter is the
+time the pipeline's user sat blocked for an item (``stats_report()``'s
+``consumer``).
 """
 from __future__ import annotations
 
@@ -76,6 +78,9 @@ class AsyncPipeline:
         self.sync = sync
         self.name = name
         self.stats = {s.name: StageStats() for s in stages}
+        # items handed to the consumer and the seconds it waited for them;
+        # written by the consuming thread only
+        self.consumer = StageStats()
         self._stat_locks = {s.name: threading.Lock() for s in stages}
         self._threads: List[threading.Thread] = []
         self._queues: List[queue.Queue] = []
@@ -99,15 +104,22 @@ class AsyncPipeline:
             return
         self.start()
         out_q = self._queues[-1]
+        c = self.consumer
         while True:
+            t0 = time.perf_counter()
             item = out_q.get()
+            c.wait_in_s += time.perf_counter() - t0
             if item is _SENTINEL:
                 if self._error is not None:
                     raise self._error
                 return
+            c.items += 1
             yield item[1]          # strip the sequence tag
 
     def _run_sync(self) -> Iterator[Any]:
+        # the consumer waits for the whole item: its source and every stage
+        c = self.consumer
+        t_item = time.perf_counter()
         for item in self.source:
             for s in self.stages:
                 st = self.stats[s.name]
@@ -115,7 +127,10 @@ class AsyncPipeline:
                 item = s.fn(item)
                 st.busy_s += time.perf_counter() - t0
                 st.items += 1
+            c.wait_in_s += time.perf_counter() - t_item
+            c.items += 1
             yield item
+            t_item = time.perf_counter()
 
     # ------------------------------------------------------------------
     def start(self) -> None:
@@ -359,4 +374,6 @@ class AsyncPipeline:
             d = self.stats[s.name].as_dict()
             d["workers"] = s.workers
             out[s.name] = d
+        out["consumer"] = {"items": self.consumer.items,
+                           "wait_in_s": self.consumer.wait_in_s}
         return out

@@ -270,7 +270,8 @@ def _cpu_backend() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _stage_arena(arena: np.ndarray) -> jnp.ndarray:
+def stage_arena(arena: np.ndarray) -> jnp.ndarray:
+    """The host arena -> one device buffer."""
     # On the CPU backend the dlpack import is the cheapest ingest path
     # (same bytes, lower dispatch overhead than device_put).  On an
     # accelerator it would land the buffer on the HOST device, so there
@@ -293,4 +294,4 @@ def device_stage(tree: Any, packed: bool = True):
     if not packed:
         return jax.tree.map(jax.device_put, tree)
     spec, arena = pack(tree)
-    return PackedBatch(spec, _stage_arena(arena))
+    return PackedBatch(spec, stage_arena(arena))

@@ -145,8 +145,10 @@ def run_gnn(args):
             print(f"[recover] {time.perf_counter() - t0:.2f}s — resuming "
                   f"at epoch {e}, batch {meta['batch_index']}")
             continue
+        phases = " ".join(f"{k}={v:.3f}s" for k, v in m["phase_s"].items())
         print(f"[epoch {e}] loss={m['loss']:.4f} {metric}={m['acc']:.3f} "
-              f"time={m['time_s']:.2f}s")
+              f"time={m['time_s']:.2f}s phase_s: {phases} "
+              f"staged_bytes={m['staged_bytes']}")
         e += 1
     if args.task == "link_prediction":
         val = tr.evaluate_lp()

@@ -22,12 +22,13 @@ The constructor options are the Fig. 14 ablation axes:
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import json
 import os
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,13 +41,39 @@ from ..checkpoint import (load_cache, load_kvstore, load_pytree, save_cache,
 from ..core.kvstore import CacheConfig, FaultInjector, NetworkModel
 from ..core.sampler import EdgeBatchSampler
 from ..graph.datasets import GraphDataset
-from ..kernels.pack import device_stage
+from ..kernels.pack import PackedBatch, pack, stage_arena
 from ..models.gnn import (GNNConfig, apply_gnn, init_gnn, init_lp_head,
                           lp_loss_from_scores, lp_metrics, lp_pair_scores,
                           lp_ranks, nc_accuracy, nc_loss)
 from ..optim import adamw_init, adamw_update
 
 TASKS = ("node_classification", "link_prediction")
+# the phases of a training step, in order; each is the span
+# ``trainer.<phase>`` and a key of ``train_epoch()``'s ``phase_s``
+PHASES = ("wait_batches", "stage", "step", "sync")
+
+
+class Span:
+    """A host span in the profiler's trace (``jax.profiler.TraceAnnotation``,
+    on the device trace's clock) whose host-clock seconds are also added
+    to ``seconds[name]``: one mechanism for the span a trace viewer shows
+    and the counter a report reads.  Off the profiler it costs the
+    annotation's construction and two clock reads."""
+
+    __slots__ = ("seconds", "name", "annotation", "t0")
+
+    def __init__(self, seconds: Dict[str, float], name: str, **metadata):
+        self.seconds = seconds
+        self.name = name
+        self.annotation = jax.profiler.TraceAnnotation(name, **metadata)
+
+    def __enter__(self):
+        self.annotation.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.seconds[self.name] += time.perf_counter() - self.t0
+        return self.annotation.__exit__(*exc)
 
 
 @dataclasses.dataclass
@@ -233,6 +260,10 @@ class DistGNNTrainer:
                                               self.node_cfg.num_classes)}
         self.opt = adamw_init(self.params)
         self._step = self._build_step()
+        # host seconds per span name and bytes staged to the device, since
+        # construction; ``train_epoch()`` reports each epoch's share
+        self.span_s: Dict[str, float] = collections.defaultdict(float)
+        self.staged_bytes = 0
         self._eval_ranks_fn = None
         self._eval_ranks_key = None
         # optimizer steps taken since construction (or since recover());
@@ -300,18 +331,36 @@ class DistGNNTrainer:
         """Stack the T trainers' host batches on a leading axis and stage
         them on the device.  Packed staging (DESIGN.md §9) stacks in host
         memory and issues ONE ``jax.device_put`` for the whole step's
-        input (then a jitted static-slice unpack); the legacy path moves
-        each leaf separately.  Device bytes are identical either way."""
+        input (then a jitted static-slice unpack), each part in a
+        ``stage.*`` span; the legacy path moves each leaf separately.
+        Device bytes are identical either way."""
         if self.job.packed_staging:
-            host = jax.tree.map(lambda *xs: np.stack(xs), *batches)
-            return device_stage(host, packed=True).unpack()
+            with Span(self.span_s, "stage.stack"):
+                host = jax.tree.map(lambda *xs: np.stack(xs), *batches)
+            with Span(self.span_s, "stage.pack"):
+                spec, arena = pack(host)
+            with Span(self.span_s, "stage.device_put"):
+                staged = PackedBatch(spec, stage_arena(arena))
+            self.staged_bytes += staged.total_bytes()
+            with Span(self.span_s, "stage.unpack"):
+                return staged.unpack()
 
         def stack_leaf(*xs):
             return jnp.stack([jnp.asarray(x) for x in xs])
-        return jax.tree.map(stack_leaf, *batches)
+        out = jax.tree.map(stack_leaf, *batches)
+        self.staged_bytes += sum(x.nbytes for x in jax.tree.leaves(out))
+        return out
 
     # ------------------------------------------------------------------
     def train_epoch(self, epoch: int) -> dict:
+        """Train one epoch.  Each step runs in four spans, in order:
+        ``trainer.wait_batches`` (the T loaders' next batches),
+        ``trainer.stage`` (``model_input()`` and ``_stack``),
+        ``trainer.step`` (dispatch of the jitted step) and
+        ``trainer.sync`` (``float`` of loss and accuracy), each carrying
+        the step's index as ``step``; no span encloses a whole step.  The
+        result holds the epoch's ``phase_s`` (host seconds per phase) and
+        ``staged_bytes``."""
         start = 0
         if self._resume is not None:
             r_epoch, r_batch = self._resume
@@ -325,6 +374,8 @@ class DistGNNTrainer:
         iters = [ld.epoch(epoch, start_batch=start) for ld in self.loaders]
         inj = self.job.fault_injector
         ckpt_every = self.job.checkpoint_interval
+        spans = self.span_s
+        span_s0, staged0 = dict(spans), self.staged_bytes
         t0 = time.perf_counter()
         losses, accs = [], []
         for k in range(start, self.batches_per_epoch):
@@ -339,12 +390,20 @@ class DistGNNTrainer:
             # killed trainer's last completed step is unambiguous
             if inj is not None:
                 inj.check_death(epoch, k)
-            batches = [next(it).model_input() for it in iters]
-            self.params, self.opt, loss, acc = self._step(
-                self.params, self.opt, self._stack(batches))
+            with Span(spans, "trainer.wait_batches", step=k):
+                items = [next(it) for it in iters]
+            with Span(spans, "trainer.stage", step=k):
+                stacked = self._stack([b.model_input() for b in items])
+            with Span(spans, "trainer.step", step=k):
+                self.params, self.opt, loss, acc = self._step(
+                    self.params, self.opt, stacked)
+            # the step holds its input until it has run; a name kept past
+            # it would hold the device copy through the next step's staging
+            del stacked
             self.global_step += 1
-            losses.append(float(loss))
-            accs.append(float(acc))
+            with Span(spans, "trainer.sync", step=k):
+                losses.append(float(loss))
+                accs.append(float(acc))
         # drain every iterator to ITS epoch boundary. With equal
         # per-trainer batch counts (node tasks, homogeneous LP) this pulls
         # nothing in non-stop mode and just exhausts finite pipelines; on
@@ -358,7 +417,11 @@ class DistGNNTrainer:
         dt = time.perf_counter() - t0
         out = {"epoch": epoch, "loss": float(np.mean(losses)),
                "acc": float(np.mean(accs)), "time_s": dt,
-               "batches": self.batches_per_epoch - start}
+               "batches": self.batches_per_epoch - start,
+               "phase_s": {p: spans[f"trainer.{p}"]
+                           - span_s0.get(f"trainer.{p}", 0.0)
+                           for p in PHASES},
+               "staged_bytes": self.staged_bytes - staged0}
         if self.task == "link_prediction":
             out["train_mrr"] = out["acc"]   # the step's aux metric is MRR
         return out

@@ -586,7 +586,8 @@ def test_loader_stats_report(homo_g):
     ld.close()
     assert rep["batches_per_epoch"] == len(ld)
     assert set(rep["stages"]) == {"sample", "cpu_prefetch",
-                                  "device_prefetch"}
+                                  "device_prefetch", "consumer"}
+    assert rep["stages"]["consumer"]["items"] == len(ld)
     assert rep["stages"]["sample"]["items"] == len(ld)
     assert rep["sampler"]["batches"] == len(ld)
     assert rep["sampler"]["coalescing_factor"] == 1.0   # untyped

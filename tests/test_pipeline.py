@@ -100,6 +100,46 @@ def test_stage_stats_recorded():
     assert p.stats_report()["w"]["items"] == 10
 
 
+def _consume(pipe, consumer_s=0.0):
+    out = []
+    for x in pipe:
+        time.sleep(consumer_s)
+        out.append(x)
+    return out
+
+
+def test_consumer_wait_counts_a_slow_stage():
+    def slow(x):
+        time.sleep(0.05)
+        return x
+    p = AsyncPipeline(range(8), [Stage("slow", slow, depth=2)])
+    assert _consume(p) == list(range(8))
+    c = p.stats_report()["consumer"]
+    assert c["items"] == 8
+    assert c["wait_in_s"] >= 0.8 * 8 * 0.05, c
+
+
+def test_consumer_wait_near_zero_behind_a_fast_stage():
+    p = AsyncPipeline(range(8), [Stage("fast", lambda x: x, depth=2)])
+    assert _consume(p, consumer_s=0.05) == list(range(8))
+    c = p.stats_report()["consumer"]
+    assert c["items"] == 8
+    # the consumer's own 0.4 s dwarfs its wait: every item was ready
+    assert c["wait_in_s"] < 0.2 * 8 * 0.05, c
+
+
+def test_consumer_wait_in_sync_mode_counts_whole_items():
+    def slow(x):
+        time.sleep(0.01)
+        return x
+    p = AsyncPipeline(range(10), [Stage("a", slow), Stage("b", slow)],
+                      sync=True)
+    assert _consume(p) == list(range(10))
+    c = p.stats_report()["consumer"]
+    assert c["items"] == 10
+    assert c["wait_in_s"] >= 0.8 * 10 * 0.02, c
+
+
 def test_pool_preserves_order_under_out_of_order_completion():
     # Worker pool stress: per-item delays force completions far out of
     # order (item 0 is the slowest of each wave); the reassembly buffer
@@ -282,6 +322,23 @@ def test_minibatch_pipeline_async_faster_than_sync(world):
         assert t_async < t_sync * 1.05, (t_async, t_sync)
     else:
         assert t_async < t_sync
+
+
+def test_consumer_counter_survives_non_stop_epochs(world):
+    ds, hp, store, tp, seeds, labels_new = world
+    sampler = DistributedSampler(hp.book, hp.partitions, [5], 32,
+                                 machine=0, transport=tp, seed=0)
+    pipe = MinibatchPipeline(sampler, store.client(0), "feat", seeds,
+                             labels=labels_new[seeds], non_stop=True,
+                             to_device=False, seed=1)
+    reports = []
+    for e in range(2):
+        assert len(list(pipe.epoch(e))) == pipe.batches_per_epoch
+        reports.append(pipe.stats_report()["consumer"])
+    pipe.stop()
+    n = pipe.batches_per_epoch
+    assert [r["items"] for r in reports] == [n, 2 * n]
+    assert 0.0 < reports[0]["wait_in_s"] < reports[1]["wait_in_s"]
 
 
 def test_pipeline_feature_correctness(world):
