@@ -41,7 +41,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Tuple
+import weakref
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -181,14 +182,63 @@ def pack(tree: Any) -> Tuple[PackSpec, np.ndarray]:
         fields.append((path, tuple(arr.shape), dt.str))
     spec = _spec_cache(tuple(sorted(fields)), none_paths)
     arena = np.zeros(spec.arena_words, dtype=np.uint32)
-    raw = arena.view(np.uint8)
-    views = {dt: raw[boff:boff + n * np.dtype(dt).itemsize].view(dt)
-             for dt, boff, n in spec.arena_layout}
+    views = _segment_views(spec, arena)
     for path, shape, dt, off, size in spec.layout:
         # ravel + canonicalization cast in one copy into the arena
         np.copyto(views[dt][off:off + size].reshape(shape), flat[path],
                   casting="unsafe")
     return spec, arena
+
+
+def _segment_views(spec: PackSpec, arena: np.ndarray
+                   ) -> Dict[str, np.ndarray]:
+    raw = arena.view(np.uint8)
+    return {dt: raw[boff:boff + n * np.dtype(dt).itemsize].view(dt)
+            for dt, boff, n in spec.arena_layout}
+
+
+def stacked_spec(batches: List[Any]) -> PackSpec:
+    """The spec of ``pack(np.stack(batches))`` without stacking them:
+    each field of ``batches[0]`` becomes ``(path, (T,) + shape, canon
+    dtype)``.  Every batch must have the same paths, shapes, dtypes and
+    ``None`` paths (§2's capacity contract); a mismatch raises."""
+    flat0, none_paths = flatten_tree(batches[0])
+    want = {p: (a.shape, a.dtype) for p, a in flat0.items()}
+    for t, b in enumerate(batches[1:], 1):
+        flat, nones = flatten_tree(b)
+        got = {p: (a.shape, a.dtype) for p, a in flat.items()}
+        if got != want or nones != none_paths:
+            raise ValueError(
+                f"batch {t} is not laid out as batch 0: fields "
+                f"{sorted(got.items())} and None paths {nones} against "
+                f"{sorted(want.items())} and {none_paths}")
+    fields = tuple(sorted(
+        (p, (len(batches),) + a.shape, _canon_dtype(a.dtype).str)
+        for p, a in flat0.items()))
+    return _spec_cache(fields, none_paths)
+
+
+def pack_into(spec: PackSpec, arena: np.ndarray, batches: List[Any]
+              ) -> np.ndarray:
+    """Write the T batches of ``spec = stacked_spec(batches)`` straight
+    into ``arena``, batch ``t`` into row ``t`` of every field, with the
+    canonicalization cast of :func:`pack`: ONE copy of the step's bytes.
+
+    ``arena`` is a uint32 buffer of ``spec.arena_words`` that was zeroed
+    when it was allocated: every byte a field owns is written here and
+    the padding words at the ends of segments are never touched, so the
+    arena equals ``pack(np.stack(batches))[1]`` byte for byte."""
+    if arena.dtype != np.uint32 or arena.shape != (spec.arena_words,):
+        raise ValueError(f"arena {arena.dtype}{arena.shape} does not hold "
+                         f"spec of {spec.arena_words} words")
+    views = _segment_views(spec, arena)
+    flats = [flatten_tree(b)[0] for b in batches]
+    for path, shape, dt, off, size in spec.layout:
+        n = size // shape[0]
+        for t, flat in enumerate(flats):
+            np.copyto(views[dt][off + t * n:off + (t + 1) * n]
+                      .reshape(shape[1:]), flat[path], casting="unsafe")
+    return arena
 
 
 @functools.lru_cache(maxsize=None)
@@ -279,6 +329,64 @@ def stage_arena(arena: np.ndarray) -> jnp.ndarray:
     if _cpu_backend():
         return jnp.from_dlpack(arena)
     return jax.device_put(arena)
+
+
+def _zeroed_words(n: int, align: int = 64) -> np.ndarray:
+    """``n`` zeroed uint32 words starting on an ``align``-byte boundary:
+    the CPU runtime then aliases every arena in place of copying it, so
+    whether the hazard that :meth:`StackedArena.stage` guards against
+    arises is not left to the allocator."""
+    raw = np.zeros(n * WORD + align, dtype=np.uint8)
+    off = -raw.ctypes.data % align
+    return raw[off:off + n * WORD].view(np.uint32)
+
+
+class StackedArena:
+    """The host arena of a training step, kept from step to step
+    (DESIGN.md §9): the T trainers' batches are written straight into it,
+    stacked on a leading axis (:func:`pack_into`), in place of
+    ``pack(np.stack(batches))``, which copies the step's bytes twice,
+    each time into fresh pages.
+
+    One arena is kept, for the spec of the last step; a new spec
+    allocates a new one (``allocs`` counts them).  Before the arena is
+    written again the last buffer staged from it must be ready, as its
+    transfer reads the arena.  The staged buffer is a copy on every
+    backend: the CPU runtime would alias the host arena (dlpack, or
+    ``device_put`` of an aligned buffer), and step k's device arrays
+    would then change under step k+1's fill."""
+
+    def __init__(self):
+        self.spec: PackSpec | None = None
+        self.arena: np.ndarray | None = None
+        self.allocs = 0
+        # weak reference to the last staged buffer (held, it would keep
+        # the step's device input alive through the next step's staging)
+        self._staged = lambda: None
+
+    def fill(self, batches: List[Any]) -> PackSpec:
+        """Pack the step's T host batches into the arena; returns the
+        stacked spec."""
+        spec = stacked_spec(batches)
+        if spec != self.spec:
+            self.spec, self.arena = spec, _zeroed_words(spec.arena_words)
+            self.allocs += 1
+        elif (last := self._staged()) is not None:
+            # train_epoch has synced on the step that read it, so this
+            # returns at once; a dead buffer was read by such a step too
+            last.block_until_ready()
+        pack_into(spec, self.arena, batches)
+        return spec
+
+    def stage(self) -> PackedBatch:
+        """The filled arena -> one device buffer that shares no memory
+        with it."""
+        if _cpu_backend():
+            buf = jnp.array(self.arena, copy=True)
+        else:
+            buf = jax.device_put(self.arena)
+        self._staged = weakref.ref(buf)
+        return PackedBatch(self.spec, buf)
 
 
 def device_stage(tree: Any, packed: bool = True):

@@ -148,7 +148,8 @@ def run_gnn(args):
         phases = " ".join(f"{k}={v:.3f}s" for k, v in m["phase_s"].items())
         print(f"[epoch {e}] loss={m['loss']:.4f} {metric}={m['acc']:.3f} "
               f"time={m['time_s']:.2f}s phase_s: {phases} "
-              f"staged_bytes={m['staged_bytes']}")
+              f"staged_bytes={m['staged_bytes']} "
+              f"staging_arena_allocs={m['staging_arena_allocs']}")
         e += 1
     if args.task == "link_prediction":
         val = tr.evaluate_lp()

@@ -41,7 +41,7 @@ from ..checkpoint import (load_cache, load_kvstore, load_pytree, save_cache,
 from ..core.kvstore import CacheConfig, FaultInjector, NetworkModel
 from ..core.sampler import EdgeBatchSampler
 from ..graph.datasets import GraphDataset
-from ..kernels.pack import PackedBatch, pack, stage_arena
+from ..kernels.pack import StackedArena
 from ..models.gnn import (GNNConfig, apply_gnn, init_gnn, init_lp_head,
                           lp_loss_from_scores, lp_metrics, lp_pair_scores,
                           lp_ranks, nc_accuracy, nc_loss)
@@ -91,9 +91,9 @@ class TrainJobConfig:
     # sampling-stage worker pool per trainer (§5.5's multiple sampling
     # workers); batches are byte-identical for any value (DESIGN.md §7)
     sample_workers: int = 1
-    # device staging (DESIGN.md §9): True = the stacked per-step batch is
-    # flattened into one contiguous host buffer per dtype and shipped with
-    # a SINGLE jax.device_put + jitted static-slice unpack; False = legacy
+    # device staging (DESIGN.md §9): True = the T batches of a step are
+    # written into one reused host arena (a segment per dtype) and shipped
+    # with a SINGLE jax.device_put + jitted static-slice unpack; False = legacy
     # per-array transfers. Bytes reaching the jitted step are identical.
     packed_staging: bool = True
     # kernel implementation for the model's aggregations (GNNConfig.impl)
@@ -264,6 +264,9 @@ class DistGNNTrainer:
         # construction; ``train_epoch()`` reports each epoch's share
         self.span_s: Dict[str, float] = collections.defaultdict(float)
         self.staged_bytes = 0
+        # the step's host arena, kept from step to step (its ``allocs``
+        # are reported per epoch as ``staging_arena_allocs``)
+        self.staging = StackedArena()
         self._eval_ranks_fn = None
         self._eval_ranks_key = None
         # optimizer steps taken since construction (or since recover());
@@ -329,18 +332,17 @@ class DistGNNTrainer:
 
     def _stack(self, batches: List[dict]) -> dict:
         """Stack the T trainers' host batches on a leading axis and stage
-        them on the device.  Packed staging (DESIGN.md §9) stacks in host
-        memory and issues ONE ``jax.device_put`` for the whole step's
-        input (then a jitted static-slice unpack), each part in a
-        ``stage.*`` span; the legacy path moves each leaf separately.
-        Device bytes are identical either way."""
+        them on the device.  Packed staging (DESIGN.md §9) writes them
+        with one copy into the trainer's reused host arena and issues ONE
+        ``jax.device_put`` for the whole step's input (then a jitted
+        static-slice unpack), each part in a ``stage.*`` span; the legacy
+        path moves each leaf separately.  Device bytes are identical
+        either way."""
         if self.job.packed_staging:
-            with Span(self.span_s, "stage.stack"):
-                host = jax.tree.map(lambda *xs: np.stack(xs), *batches)
             with Span(self.span_s, "stage.pack"):
-                spec, arena = pack(host)
+                self.staging.fill(batches)
             with Span(self.span_s, "stage.device_put"):
-                staged = PackedBatch(spec, stage_arena(arena))
+                staged = self.staging.stage()
             self.staged_bytes += staged.total_bytes()
             with Span(self.span_s, "stage.unpack"):
                 return staged.unpack()
@@ -359,8 +361,9 @@ class DistGNNTrainer:
         ``trainer.step`` (dispatch of the jitted step) and
         ``trainer.sync`` (``float`` of loss and accuracy), each carrying
         the step's index as ``step``; no span encloses a whole step.  The
-        result holds the epoch's ``phase_s`` (host seconds per phase) and
-        ``staged_bytes``."""
+        result holds the epoch's ``phase_s`` (host seconds per phase),
+        ``staged_bytes`` and ``staging_arena_allocs`` (host arenas
+        allocated: 1 in the first epoch, 0 once the arena is reused)."""
         start = 0
         if self._resume is not None:
             r_epoch, r_batch = self._resume
@@ -376,6 +379,7 @@ class DistGNNTrainer:
         ckpt_every = self.job.checkpoint_interval
         spans = self.span_s
         span_s0, staged0 = dict(spans), self.staged_bytes
+        allocs0 = self.staging.allocs
         t0 = time.perf_counter()
         losses, accs = [], []
         for k in range(start, self.batches_per_epoch):
@@ -421,7 +425,8 @@ class DistGNNTrainer:
                "phase_s": {p: spans[f"trainer.{p}"]
                            - span_s0.get(f"trainer.{p}", 0.0)
                            for p in PHASES},
-               "staged_bytes": self.staged_bytes - staged0}
+               "staged_bytes": self.staged_bytes - staged0,
+               "staging_arena_allocs": self.staging.allocs - allocs0}
         if self.task == "link_prediction":
             out["train_mrr"] = out["acc"]   # the step's aux metric is MRR
         return out

@@ -15,8 +15,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.pack import (PackSpec, PackedBatch, device_stage,
-                                flatten_tree, pack, unflatten_tree, unpack)
+from repro.kernels.pack import (PackSpec, PackedBatch, StackedArena,
+                                device_stage, flatten_tree, pack, pack_into,
+                                stacked_spec, unflatten_tree, unpack)
 
 ops = importlib.import_module("repro.kernels.pack.ops")
 
@@ -184,3 +185,89 @@ def test_scalar_and_zero_dim_leaves():
     out = device_stage(tree, packed=True).unpack()
     assert out["s"].shape == () and float(out["s"]) == 2.5
     assert out["z"].shape == () and int(out["z"]) == 7
+
+
+# ---- the stacked fill into a reused arena (the training step's path) ----
+
+def _lp_tree(seed=0):
+    """A link-prediction-shaped tree: endpoint and negative indices as
+    int64 (canonicalized), a bool pair mask, a None etype slot."""
+    rng = np.random.default_rng(seed)
+    return dict(
+        input_feats=rng.standard_normal((24, 8)).astype(np.float32),
+        seed_mask=rng.integers(0, 2, 12).astype(bool),
+        pos_u=rng.integers(0, 12, 4).astype(np.int64),
+        pos_v=rng.integers(0, 12, 4).astype(np.int64),
+        neg_v=rng.integers(0, 12, (4, 3)).astype(np.int64),
+        pair_mask=rng.integers(0, 2, 4).astype(bool),
+        edge_etypes=None,
+        blocks=[dict(edge_src=rng.integers(0, 24, 30).astype(np.int32),
+                     edge_dst=rng.integers(0, 12, 30).astype(np.int32),
+                     edge_mask=rng.integers(0, 2, 30).astype(bool),
+                     edge_types=None)])
+
+
+_TREES = {"node": _batch_tree, "link": _lp_tree}
+
+
+def _stacked(batches):
+    return jax.tree.map(lambda *xs: np.stack(xs), *batches)
+
+
+@pytest.mark.parametrize("kind", sorted(_TREES))
+@pytest.mark.parametrize("T", [1, 2, 4])
+def test_pack_into_equals_pack_of_stack(kind, T):
+    """One copy into the arena gives the bytes of ``np.stack`` then
+    ``pack``, and the same unpacked device tree; refilling a used arena
+    with other batches overwrites every byte a field owns."""
+    make = _TREES[kind]
+    batches = [make(10 * T + t) for t in range(T)]
+    want_spec, want = pack(_stacked(batches))
+    spec = stacked_spec(batches)
+    assert spec is want_spec
+    arena = np.zeros(spec.arena_words, np.uint32)
+    assert pack_into(spec, arena, batches) is arena
+    assert arena.tobytes() == want.tobytes()
+    got = PackedBatch(spec, jnp.array(arena)).unpack()
+    assert _flat_bytes(got) == _flat_bytes(device_stage(_stacked(batches),
+                                                        packed=False))
+    others = [make(100 + 10 * T + t) for t in range(T)]
+    pack_into(spec, arena, others)
+    assert arena.tobytes() == pack(_stacked(others))[1].tobytes()
+
+
+@pytest.mark.parametrize("change", ["shape", "dtype", "none", "path"])
+def test_stacked_spec_refuses_batches_laid_out_differently(change):
+    a, b = _batch_tree(1), _batch_tree(2)
+    if change == "shape":
+        b["input_feats"] = b["input_feats"][:15]
+    elif change == "dtype":
+        b["seeds"] = b["seeds"].astype(np.int32)
+    elif change == "none":
+        b["labels"] = np.zeros(16, np.int32)
+    else:
+        b["extra"] = np.zeros(3, np.float32)
+    with pytest.raises(ValueError, match="batch 1"):
+        stacked_spec([a, b])
+
+
+def test_stacked_arena_reused_per_spec_and_staged_as_a_copy():
+    """The arena is allocated once per spec, and a staged buffer never
+    aliases it: step k's device bytes survive step k+1's fill."""
+    st_ = StackedArena()
+    steps = [[_batch_tree(20 + 2 * k + t) for t in range(2)]
+             for k in range(3)]
+    st_.fill(steps[0])
+    arena = st_.arena
+    staged = st_.stage()
+    tree = staged.unpack()
+    st_.fill(steps[1])
+    assert st_.arena is arena and st_.allocs == 1
+    want = pack(_stacked(steps[0]))[1]
+    assert np.asarray(staged.buffers).tobytes() == want.tobytes()
+    assert _flat_bytes(tree) == _flat_bytes(
+        device_stage(_stacked(steps[0]), packed=False))
+    assert np.asarray(st_.stage().buffers).tobytes() == \
+        pack(_stacked(steps[1]))[1].tobytes()
+    st_.fill(steps[2][:1])       # another spec: a new arena
+    assert st_.allocs == 2 and st_.arena is not arena

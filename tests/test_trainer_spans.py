@@ -1,9 +1,9 @@
 """The training loop's spans and counters, read back from a profiler trace
 on the CPU: each step's four phase spans in order on the training thread,
 the ``stage.*`` children inside ``trainer.stage``, exact event names with
-the step index as metadata, the epoch's ``phase_s`` and
-``staged_bytes``, and a step's staged input released before the next
-step stages."""
+the step index as metadata, the epoch's ``phase_s``, ``staged_bytes``
+and ``staging_arena_allocs``, and a step's staged input released before
+the next step stages."""
 import glob
 import os
 
@@ -18,8 +18,7 @@ from repro.models.gnn import GNNConfig
 from repro.training.trainer import PHASES, DistGNNTrainer, TrainJobConfig
 
 PHASE_SPANS = tuple(f"trainer.{p}" for p in PHASES)
-STAGE_CHILDREN = ("stage.stack", "stage.pack", "stage.device_put",
-                  "stage.unpack")
+STAGE_CHILDREN = ("stage.pack", "stage.device_put", "stage.unpack")
 STEPS = 2
 
 
@@ -84,7 +83,10 @@ def test_stage_children_inside_stage(traced):
 
 def test_event_names_are_exact(traced):
     _, events, _, _ = traced
-    assert {e[0] for e in events} == set(PHASE_SPANS + STAGE_CHILDREN)
+    names = {e[0] for e in events}
+    assert names == set(PHASE_SPANS + STAGE_CHILDREN)
+    # the T batches are written straight into the arena: nothing stacks
+    assert "stage.stack" not in names
 
 
 def test_phase_seconds_and_staged_bytes(traced):
@@ -97,6 +99,7 @@ def test_phase_seconds_and_staged_bytes(traced):
     spec, arena = pack(jax.tree.map(lambda *xs: np.stack(xs), *seen[0]))
     assert out["staged_bytes"] == STEPS * spec.total_bytes()
     assert spec.total_bytes() <= arena.nbytes
+    assert out["staging_arena_allocs"] == 1
 
 
 def test_step_input_freed_before_the_next_step_stages(traced):
