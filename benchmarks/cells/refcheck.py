@@ -34,6 +34,13 @@ cell's ``limits/<cell>.json`` names those it holds to a limit):
 made of the graph the benchmark made: sampled feature rows as the
 loader served them, labels, sampled edges, and each block's
 destinations the next block's sources.
+
+On a typed cell the relation of every edge slot is the harness's own
+(``world.relation_slots``, from the configuration's fanouts and the
+traffic's relations), never the ``edge_types`` the program served: the
+reference aggregates each relation over the slots the harness gives it,
+and a sampled edge counts as the graph's only as ``(relation of its
+slot, u, v)``, so an edge in another relation's slots is a mismatch.
 """
 from __future__ import annotations
 
@@ -96,13 +103,26 @@ def served(mb, rng: np.random.Generator, rows: int = 4096) -> Served:
                  "edge_mask": b.edge_mask} for b in mb.blocks])
 
 
-def model_batch(s: Served, feats: np.ndarray) -> dict:
+def slot_relations(slots: list) -> list:
+    """Per layer, the relation id of every edge slot by the static
+    offsets of ``world.relation_slots``."""
+    return [np.repeat(np.arange(len(offs) - 1, dtype=np.int32),
+                      np.diff(offs)) for offs in slots]
+
+
+def model_batch(s: Served, feats: np.ndarray,
+                slots: Optional[list] = None) -> dict:
     """The model's input for a served mini-batch, its feature rows taken
-    from ``feats``, the graph's features in the program's node order."""
+    from ``feats``, the graph's features in the program's node order; on
+    a typed cell each block has ``edge_rel``, the relation of every edge
+    slot by ``slots``."""
+    blocks = [{k: b[k] for k in ("edge_src", "edge_dst", "edge_mask")}
+              for b in s.blocks]
+    if slots is not None:
+        for b, rel in zip(blocks, slot_relations(slots)):
+            b["edge_rel"] = rel
     return {"input_feats": feats[s.input_gids], "labels": s.labels,
-            "seed_mask": s.seed_mask,
-            "blocks": [{k: b[k] for k in ("edge_src", "edge_dst",
-                                          "edge_mask")} for b in s.blocks]}
+            "seed_mask": s.seed_mask, "blocks": blocks}
 
 
 def _loss(arch, cfg, caps, dtype, params, batch):
@@ -133,20 +153,23 @@ class Reference:
     ``matmul_precision``, is the reference; bfloat16 is the control.
     ``precision`` overrides the matmul precision.  ``fault`` plants one
     of :data:`FAULTS` in it, so that what each fault does to the
-    compared numbers can be read."""
+    compared numbers can be read.  ``slots``: a typed cell's relation
+    slots (:func:`model_batch`)."""
 
     def __init__(self, arch, cfg: dict, caps: list, lr: float,
                  dtype=jnp.float32, fault: Optional[str] = None,
-                 precision: Optional[str] = None):
+                 precision: Optional[str] = None,
+                 slots: Optional[list] = None):
         if fault is not None and fault not in FAULTS:
             raise ValueError(f"unknown fault {fault!r}; have {FAULTS}")
         self.lr, self.fault, self.dtype = lr, fault, dtype
+        self.slots = slots
         self.precision = precision or cfg["matmul_precision"]
         self._grad = jax.jit(jax.value_and_grad(
             lambda p, b: _loss(arch, cfg, caps, dtype, p, b)))
 
     def _trainer_batch(self, s: Served, t: int) -> dict:
-        b = model_batch(s, self.feats)
+        b = model_batch(s, self.feats, self.slots)
         if self.fault == "half_batch":
             mask = np.array(b["seed_mask"])
             mask[len(mask) // 2:] = False
@@ -227,16 +250,24 @@ def compare(got: dict, ref: dict) -> dict:
 
 
 def batch_mismatches(steps: list, g, new2old: np.ndarray,
-                     rng: np.random.Generator, rows: int = 4096) -> int:
+                     rng: np.random.Generator, rows: int = 4096,
+                     slots: Optional[list] = None) -> int:
     """Rows of the served mini-batches that are not rows of the graph
     ``g`` (a ``world.Graph``): the sampled feature rows, every live
     seed's label, ``rows`` live edges a block drawn from ``rng``, and
     every block's destinations against the next block's sources.
-    ``new2old`` maps the program's node ids to ``g``'s."""
+    ``new2old`` maps the program's node ids to ``g``'s.  On a typed
+    graph an edge is ``(relation of its slot by slots, u, v)``."""
     n = g.num_nodes
     if not np.array_equal(np.sort(new2old), np.arange(n)):
         return n
     keys = g.edge_keys()
+    rels = None
+    if g.etypes is not None:
+        if slots is None:
+            raise ValueError("a typed graph's edges are checked by the "
+                             "relation of their slots")
+        rels = slot_relations(slots)
     bad = 0
     for batches in steps:
         for s in batches:
@@ -250,8 +281,11 @@ def batch_mismatches(steps: list, g, new2old: np.ndarray,
                 e = e[rng.integers(0, len(e), rows)] if len(e) else e
                 u = new2old[b["src_gids"][b["edge_src"][e]]]
                 v = new2old[b["src_gids"][b["edge_dst"][e]]]
-                pos = np.searchsorted(keys, u * n + v)
-                hit = keys[np.minimum(pos, len(keys) - 1)] == u * n + v
+                key = u * n + v
+                if rels is not None:
+                    key = (rels[l][e].astype(np.int64) * n + u) * n + v
+                pos = np.searchsorted(keys, key)
+                hit = keys[np.minimum(pos, len(keys) - 1)] == key
                 bad += int((~hit).sum())
                 if l + 1 < len(s.blocks):
                     k = s.blocks[l + 1]["num_src"]

@@ -7,7 +7,11 @@
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a model
 configuration (``configs/<name>.json``) trained on a traffic mix
 (``traffic/<name>.json``, the graph and its labelled seeds) through the
-program's normal path, ``DistGNNTrainer.train_epoch``.
+program's normal path, ``DistGNNTrainer.train_epoch``.  The traffic
+file states an R-MAT or a typed graph (``world.py``); a typed cell's
+configuration gives each layer's fanouts per relation, and the check
+takes each edge slot's relation from the harness's own
+``world.relation_slots``.
 
 Set-up (``setup_s``, from process start to the window): the persistent
 compilation cache at ``<checkout>/.jax_cache`` (or
@@ -17,9 +21,13 @@ user gets by default and ``--seed`` as ``TrainJobConfig.seed``, the
 weights made on the device from ``--seed`` and handed to the trainer,
 then ``train_epoch`` from epoch 0 until the first three steps and one
 step after the compile have run.  Those three steps are recorded for the
-check.  The window then calls ``train_epoch`` for the following epochs
-until ``--seconds`` have passed, and ends on the boundary of the epoch
-that crosses it.  ``--trace 1`` records the window with the profiler and
+check.  Then the loaders are stopped, which empties their queues, and
+the following epochs run until at least ``SETTLE_STEPS`` steps have come
+through the restarted loaders (``settle``), so that the window opens on
+the loaders' steady state and not on queues of a size that depends on
+how long the compile took.  The window then calls ``train_epoch`` for
+the following epochs until ``--seconds`` have passed, and ends on the
+boundary of the epoch that crosses it.  ``--trace 1`` records the window with the profiler and
 reports the per-layer metrics (``metrics/<name>.py``) in place of the
 end-to-end ones.
 
@@ -61,6 +69,7 @@ import world  # noqa: E402
 import xplane  # noqa: E402
 
 REF_STEPS = 3
+SETTLE_STEPS = 4
 KERNEL = "fused_gather_aggregate"
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",)
 
@@ -136,11 +145,16 @@ class Recorder:
 
 class EdgeCounter:
     """Counts the live edges of every layer of every mini-batch the
-    loaders serve while it is open (the traced window only)."""
+    loaders serve while it is open (the traced window only); on a typed
+    cell, per layer, ``{relation: live edges}`` by the relation slots."""
 
-    def __init__(self, trainer, num_layers: int):
+    def __init__(self, trainer, num_layers: int,
+                 slots: Optional[list] = None,
+                 relations: Optional[list] = None):
         self.trainer = trainer
-        self.live = [0] * num_layers
+        self.slots, self.relations = slots, relations
+        self.live = ([0] * num_layers if slots is None else
+                     [dict.fromkeys(relations, 0) for _ in range(num_layers)])
         for ld in trainer.loaders:
             ld.epoch = self._count(ld.epoch)
 
@@ -150,7 +164,13 @@ class EdgeCounter:
         def epoch(*args, **kwargs):
             for item in serve(*args, **kwargs):
                 for l, b in enumerate(item.minibatch.blocks):
-                    self.live[l] += int(np.count_nonzero(b.edge_mask))
+                    if self.slots is None:
+                        self.live[l] += int(np.count_nonzero(b.edge_mask))
+                        continue
+                    offs = self.slots[l]
+                    for r, rel in enumerate(self.relations):
+                        self.live[l][rel] += int(np.count_nonzero(
+                            b.edge_mask[offs[r]:offs[r + 1]]))
                 yield item
         return epoch
 
@@ -166,7 +186,9 @@ class Session:
     graph: world.Graph
     trainer: object
     arch: object
+    conf: dict              # the configuration as the reference reads it
     caps: list
+    slots: Optional[list]   # a typed cell's relation slots, else None
     params0: object
     recorder: Recorder
     next_epoch: int
@@ -228,7 +250,8 @@ def build(cell: world.Cell, seed: int,
     log(f"trainer: {tr.num_trainers} trainers, {tr.batches_per_epoch} "
         f"batches/epoch, {time.perf_counter() - t:.3f} s")
     arch = arch_module(cell.config["arch"])
-    p0 = weights(arch, cell.config, cfg.in_dim, cfg.num_classes, seed)
+    conf = world.arch_config(cell.config, graph)
+    p0 = weights(arch, conf, cfg.in_dim, cfg.num_classes, seed)
     if (jax.tree.structure(p0) != jax.tree.structure(tr.params)
             or any(a.shape != b.shape for a, b in zip(
                 jax.tree.leaves(p0), jax.tree.leaves(tr.params)))):
@@ -247,10 +270,34 @@ def build(cell: world.Cell, seed: int,
         rec.close()
     log(f"warm-up: {steps} steps in {epoch} epochs, "
         f"{time.perf_counter() - t:.3f} s, {compiles[0]} programs built")
+    t = time.perf_counter()
+    epoch, steps = settle(tr, epoch)
+    log(f"settle: {steps} steps, {time.perf_counter() - t:.3f} s")
+    slots = (None if graph.schema is None else world.relation_slots(
+        cfg.batch_size, cfg.fanouts, graph.relations))
     return Session(cell=cell, seed=seed, graph=graph, trainer=tr, arch=arch,
+                   conf=conf,
                    caps=world.capacities(cfg.batch_size, cfg.fanouts),
-                   params0=params0, recorder=rec, next_epoch=epoch,
-                   compiles=compiles)
+                   slots=slots, params0=params0, recorder=rec,
+                   next_epoch=epoch, compiles=compiles)
+
+
+def settle(tr, epoch: int) -> tuple:
+    """Bring the loaders to the state of a long job before the window.
+
+    The loaders fill their queues while the warm-up compiles, and by how
+    much depends on how long that took; a window that opened on full
+    queues would read their drain.  So every loader is stopped, which
+    drops what it holds, and the trainer runs on from empty queues for at
+    least ``SETTLE_STEPS`` steps, whole epochs, past the first batches'
+    latency.  Returns the next epoch and the steps run."""
+    for ld in tr.loaders:
+        ld.stop()
+    steps = 0
+    while steps < SETTLE_STEPS:
+        steps += tr.train_epoch(epoch)["batches"]
+        epoch += 1
+    return epoch, steps
 
 
 def _snapshot(tr) -> tuple:
@@ -271,7 +318,8 @@ def measure(s: Session, seconds: float, trace: bool) -> Window:
     import jax
     tr = s.trainer
     stages0, transport0 = _snapshot(tr)
-    counter = EdgeCounter(tr, len(s.caps)) if trace else None
+    counter = (EdgeCounter(tr, len(s.caps), s.slots, s.graph.relations)
+               if trace else None)
     tdir = tempfile.mkdtemp(prefix="cells-trace-") if trace else None
     compiles0 = s.compiles[0]
     steps = failed = epochs = 0
@@ -306,10 +354,10 @@ def measure(s: Session, seconds: float, trace: bool) -> Window:
                           recursive=True)
         summary = xplane.summarize(xplane.load_xplane(files[0]), [KERNEL])
         shutil.rmtree(tdir, ignore_errors=True)
-        calls = s.arch.gather_calls(s.cell.config, cfg.in_dim,
+        calls = s.arch.gather_calls(s.conf, cfg.in_dim,
                                     cfg.num_classes, s.caps, counter.live)
         least = counts.least_seconds(calls, peak)
-    flops = tr.num_trainers * s.arch.flops(s.cell.config, cfg.in_dim,
+    flops = tr.num_trainers * s.arch.flops(s.conf, cfg.in_dim,
                                            cfg.num_classes, s.caps)
     seeds = epochs * sum(min(len(x), tr.batches_per_epoch * cfg.batch_size)
                          for x in tr.trainer_seeds)
@@ -347,8 +395,8 @@ def train_reference(s: Session, new2old, **kw) -> dict:
     """The reference (or, with ``dtype`` or ``fault``, what stands in
     the program's place) over the recorded steps."""
     from refcheck import Reference
-    return Reference(s.arch, s.cell.config, s.caps,
-                     float(s.cell.config["lr"]), **kw).train(
+    return Reference(s.arch, s.conf, s.caps, float(s.conf["lr"]),
+                     slots=s.slots, **kw).train(
         s.params0, s.recorder.steps(), s.graph.feats[new2old],
         s.graph.num_classes)
 
@@ -361,7 +409,7 @@ def program_numbers(s: Session, new2old) -> tuple:
     numbers = compare(program_readings(s), ref)
     rng = np.random.default_rng([s.seed & 0xFFFFFFFF, s.seed >> 32, 7])
     numbers["batch_mismatches"] = batch_mismatches(
-        s.recorder.steps(), s.graph, new2old, rng)
+        s.recorder.steps(), s.graph, new2old, rng, slots=s.slots)
     return numbers, ref
 
 
